@@ -19,7 +19,7 @@ KIND_INS, KIND_SET, KIND_DEL, KIND_INC = 0, 1, 2, 3
 HEAD_PARENT = -1  # parent-actor encoding for the virtual list head ('_head')
 
 # The device tier's numeric envelope. Every device column is int32 (the
-# TPU emulates int64; docs/MEASUREMENTS.md), elemId keys pack as
+# TPU emulates int64; `profile_bench.py --int64`), elemId keys pack as
 # (actor_rank << 32 | ctr) into int64 (engine/host_index.py), and actor
 # ranks reproduce the reference's string ordering (op_set.js:432-436) as
 # int32 comparisons — so counters, seqs, and ranks past 2^31-1 would

@@ -1456,8 +1456,8 @@ class CausalDeviceDoc:
             if self.packed_residual_writeback:
                 # ONE packed h2d upload: with the packed slow_info fetch
                 # this makes the whole residual register residue exactly
-                # one d2h round trip + one upload (the WAN-tunnel shape
-                # cfg5b bounds)
+                # one d2h round trip + one upload (the shape cfg5b
+                # bounds)
                 from ..ops.ingest import (donation_enabled,
                                           scatter_registers_packed,
                                           scatter_registers_packed_donated)
@@ -1489,7 +1489,7 @@ class CausalDeviceDoc:
 
     def _fetch_mirrors(self, keys) -> dict:
         """Host numpy mirrors of device tables, fetched as ONE packed
-        transfer (RTT-bound on remote-attached chips). bool tables come
+        transfer (one host<->device round trip). bool tables come
         back as bool; everything else int32."""
         from ..ops.ingest import pack_rows
         import jax.numpy as jnp

@@ -9,7 +9,7 @@ the op columns, the dep-closure admission partition, packed head keys,
 the (9, R) descriptor template — was re-derived per document even though
 the whole touched population carries the SAME wire shape (cfg12's text
 population: per-doc host planning floored the measurable asymmetry at
-3.43x with no acceptance bar, docs/MEASUREMENTS.md).
+3.43x on cpu with no acceptance bar).
 
 This module amortizes host planning ACROSS the doc population the way
 `engine/stacked.py` amortized dispatch:

@@ -11,7 +11,7 @@ the host, where the op columns originate anyway. Two facts make this cheap:
 - lookups are numpy ``searchsorted`` over packed range starts — C-speed
   binary search, no device round trip, no int64 emulation on the TPU (int64
   sorts/searches run emulated and severalfold slower than int32 on v5e;
-  design assumption, docs/MEASUREMENTS.md).
+  design assumption, `profile_bench.py --int64` measures it).
 
 Keys pack as (actor_rank << 32 | ctr); counters stay < 2^31 so keys within a
 range are consecutive integers and slot arithmetic is a subtraction.
@@ -87,8 +87,8 @@ def pack_keys(actor: np.ndarray, ctr: np.ndarray) -> np.ndarray:
     """(actor_rank, ctr) -> packed int64 key. Loud on envelope overflow:
     a ctr or rank past 2^31-1 (or negative) would corrupt the packing —
     adjacent keys would collide or reorder — instead of failing, so the
-    guard raises OverflowError before any key escapes (VERDICT r5 item 3;
-    tests/test_int32_guards.py)."""
+    guard raises OverflowError before any key escapes
+    (tests/test_int32_guards.py)."""
     check_int32_envelope("elemId counter", ctr)
     check_int32_envelope("actor rank", actor)
     return (actor.astype(np.int64) << 32) | ctr.astype(np.int64)

@@ -1,11 +1,9 @@
 """Host-side planning parallelism + the pipelined ingestion driver.
 
-Round-5 profiling (docs/PROFILE_r5.md, BENCH_LAST_GOOD.json) put the
-device commit region at ~87 ms while end-to-end trailed 3.5x behind it:
-host planning (`prepare_s` 0.215 s) and the d2h text pull each outweigh
-the commit, and the in-process overlap schedule LOST to serial even
-though the same seam paid 1.697x on separate processors (cfg5d on-chip).
-This module closes the planning half of that gap:
+Round-5 profiling put host planning (`prepare_s`) and the d2h text pull
+each above the device commit region of the headline merge, and the
+in-process overlap schedule lost to serial. This module closes the
+planning half of that gap:
 
 - `planner_pool()` — one small shared ThreadPoolExecutor. Every heavy
   planning pass (the native run-detection walker, numpy column passes)

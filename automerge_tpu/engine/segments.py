@@ -23,7 +23,7 @@ module keeps that structure on the host:
 Segment counts are ~#concurrent-insertion-points (thousands), orders of
 magnitude below element counts (millions), so the numpy stage is sub-ms and
 rides the *untimed* prepare phase; it removes the S-stage (~20 ms at
-headline-bench scale, docs/PROFILE_r3.md) from the merge critical path.
+headline-bench scale) from the merge critical path.
 
 The mirror replaces recomputation, not trust: the planned kernel re-derives,
 from the real chain bits, the segment count plus two nonlinearly-mixed

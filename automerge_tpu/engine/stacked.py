@@ -5,8 +5,8 @@ many small sections — routes ONE causal round across many small
 per-object engine docs. The per-object path (backend/device.py
 `_distribute` -> `doc.apply_changes` per object) pays 1-2 jitted
 programs plus their h2d staging per (object, round): ~270 tiny
-device_puts for a 400-op board merge, the recorded cfg4 ceiling
-(docs/MEASUREMENTS.md). This module executes the SAME rounds as a
+device_puts for a 400-op board merge, the recorded cfg4 ceiling.
+This module executes the SAME rounds as a
 constant number of stacked device programs per round, independent of
 object count — PAM's batch-parallel-over-many-keys shape (PAPERS.md)
 applied to the object axis:

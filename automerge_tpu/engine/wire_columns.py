@@ -7,7 +7,7 @@ and every `prepare_batch` re-derived the same facts about the same
 (immutable) batch — dense actor ids, dep grouping, the all-concurrent
 shape test — with per-change dict lookups and Python walks. At headline
 scale (10k changes) that re-derivation, not the op math, dominated host
-planning (docs/PROFILE_r7.md).
+planning (round-7 cpu profile).
 
 `ColumnarChangeBatch` is the missing half: int32 struct-of-arrays for the
 per-change metadata, decoded ONCE at the protocol boundary and cached on

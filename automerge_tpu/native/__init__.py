@@ -3,11 +3,14 @@
 The compute path is JAX/XLA (ops/); this package holds the host runtime
 pieces where native code pays. `codec.cpp` decodes JSON change lists (the
 sync wire format) straight into the engine's columnar batch arrays
-(measured 3.5x the per-op Python decoder - JSON lexing dominates both -
-and the run-detection walker 18x the numpy path; docs/MEASUREMENTS.md).
+(measured on cpu: 3.5x the per-op Python decoder - JSON lexing dominates
+both - and the run-detection walker 18x the numpy path).
 
 The library builds lazily with g++ (no pybind11 — plain ctypes over an
-extern-C API) and caches next to the source; every entry point degrades to
+extern-C API) and caches next to the source, keyed on a hash of the
+source plus the build flags: a `build/` copied from another tree (the
+chip machine gets a copy of the working tree) is rebuilt unless it came
+from exactly this source. Every entry point degrades to
 the pure-Python decoder when the toolchain or the .so is unavailable, or
 when the batch contains shapes the native scope excludes (rich values,
 non-list objects) — correctness never depends on the native tier.
@@ -16,6 +19,7 @@ non-list objects) — correctness never depends on the native tier.
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import json
 import os
 import subprocess
@@ -55,7 +59,18 @@ def _build_flags() -> list:
     return flags
 
 
-_FLAGS_STAMP = os.path.join(_HERE, "build", "build_flags.txt")
+_KEY_STAMP = os.path.join(_HERE, "build", "build_key.txt")
+
+
+def _build_key(flags: list) -> str:
+    """sha256 of the codec source plus the flags: the cache key of the
+    built library (mtimes say nothing about where a copied .so came
+    from)."""
+    h = hashlib.sha256()
+    with open(_SRC, "rb") as fh:
+        h.update(fh.read())
+    h.update(" ".join(flags).encode())
+    return h.hexdigest()
 
 
 def _load():
@@ -68,22 +83,24 @@ def _load():
             return _lib
         try:
             flags = _build_flags()
-            # the flags are part of the cache key: an mtime-only check
-            # would keep serving a stale -O2 build (or an AVX2 build to
-            # a host that can't run it) forever
+            key = _build_key(flags)
             try:
-                with open(_FLAGS_STAMP) as fh:
-                    stamp_current = fh.read() == " ".join(flags)
+                with open(_KEY_STAMP) as fh:
+                    stamp_current = fh.read() == key
             except OSError:
                 stamp_current = False
-            if (not os.path.exists(_SO) or not stamp_current
-                    or os.path.getmtime(_SO) < os.path.getmtime(_SRC)):
+            if not os.path.exists(_SO) or not stamp_current:
                 os.makedirs(os.path.dirname(_SO), exist_ok=True)
+                # build beside the target and rename into place: several
+                # processes (test workers) may build at once
+                tmp = f"{_SO}.{os.getpid()}.tmp"
                 subprocess.run(
-                    ["g++", *flags, _SRC, "-o", _SO],
+                    ["g++", *flags, _SRC, "-o", tmp],
                     check=True, capture_output=True, timeout=120)
-                with open(_FLAGS_STAMP, "w") as fh:
-                    fh.write(" ".join(flags))
+                os.replace(tmp, _SO)
+                with open(f"{_KEY_STAMP}.{os.getpid()}.tmp", "w") as fh:
+                    fh.write(key)
+                os.replace(f"{_KEY_STAMP}.{os.getpid()}.tmp", _KEY_STAMP)
             lib = ctypes.CDLL(_SO)
             lib.amtpu_parse.restype = ctypes.c_void_p
             lib.amtpu_parse.argtypes = [ctypes.c_char_p, ctypes.c_long,

@@ -7,7 +7,7 @@
 // consumes (engine/columnar.py:TextChangeBatch). The Python decoder loops
 // per op (~1us/op); this decoder is a single-pass recursive-descent parse
 // into preallocated columns (measured 484 ns/op, 3.5x the Python
-// decoder - JSON lexing dominates both; docs/MEASUREMENTS.md).
+// decoder on cpu - JSON lexing dominates both).
 //
 // Scope: ins/set/del/inc ops on ONE list/text object, with single-char
 // string values or integer values. Anything else (nested objects, rich

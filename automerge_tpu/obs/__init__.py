@@ -30,7 +30,7 @@ scoped ``with obs.tracing(): ...``. Export with `obs.write_trace(path)`
 (Chrome trace-event JSON — load at https://ui.perfetto.dev) and read
 aggregates with `obs.metrics_snapshot()`.
 
-Category taxonomy (full schema in docs/INTERNALS.md §11):
+Category catalogue (full schema in docs/INTERNALS.md §11):
 
   plan    host planning: prepare_batch / admission / wire decode
   commit  commit_prepared (args carry n_rounds + dispatch/sync delta)

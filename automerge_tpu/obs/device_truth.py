@@ -71,7 +71,6 @@ import os
 import threading
 import time
 from collections import OrderedDict, deque
-from typing import Optional
 
 #: THE fast-path gate (same shape as obs.ENABLED / lineage.ENABLED):
 #: instrumented kernels read this module attribute per call. Default ON —
@@ -735,25 +734,42 @@ def attribute_device_time(label_calls: dict, total_s: float) -> dict:
             for label, w in sorted(weights.items())}
 
 
-def roofline_seconds(label_calls: dict,
-                     peak_flops: Optional[float] = None,
-                     peak_bw: Optional[float] = None) -> dict:
+#: Published peak rates of one chip, keyed by JAX's ``device_kind``.
+#: TPU v5e ("TPU v5 lite"): Google Cloud documentation, "TPU v5e" —
+#: 197 TFLOP/s bf16, 819 GB/s HBM bandwidth. A kind not listed here has
+#: no roofline: `peak_rates` raises rather than assume one.
+PEAK_RATES = {
+    "TPU v5 lite": {"flops_per_s": 197e12, "bytes_per_s": 819e9},
+}
+
+
+def peak_rates(device_kind: str) -> dict:
+    """{"flops_per_s", "bytes_per_s"} of `device_kind` from PEAK_RATES;
+    an unknown kind raises ValueError."""
+    try:
+        return PEAK_RATES[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no published peak rates for device kind {device_kind!r}; "
+            "add them to obs/device_truth.PEAK_RATES with their "
+            "source") from None
+
+
+def roofline_seconds(label_calls: dict) -> dict:
     """Cost-model roofline floor for the given per-label call counts:
-    sum over labels of calls x max(flops/peak_flops, bytes/peak_bw).
-    Peaks come from AMTPU_PEAK_FLOPS / AMTPU_PEAK_BYTES_PER_S or rough
-    per-platform defaults — on cpu the ratio is a sanity band, not a
-    measurement (XLA:CPU cost analysis ignores cache locality; §19.4);
-    chip runs export their chip's datasheet numbers."""
+    sum over labels of calls x max(flops/peak_flops, bytes/peak_bw),
+    with the peaks of this process's device kind (`peak_rates`). On the
+    CPU there is no roofline — it is not a device metric — so
+    ``seconds`` is None ("not measured") and ``per_label`` is empty."""
     import jax
-    platform = jax.devices()[0].platform
-    if peak_flops is None:
-        peak_flops = float(os.environ.get(
-            "AMTPU_PEAK_FLOPS",
-            "2e14" if platform == "tpu" else "5e10"))
-    if peak_bw is None:
-        peak_bw = float(os.environ.get(
-            "AMTPU_PEAK_BYTES_PER_S",
-            "8e11" if platform == "tpu" else "3e10"))
+    dev = jax.devices()[0]
+    out = {"seconds": None, "per_label": {}, "peak_flops": None,
+           "peak_bytes_per_s": None, "platform": dev.platform,
+           "device_kind": dev.device_kind}
+    if dev.platform == "cpu":
+        return out
+    peaks = peak_rates(dev.device_kind)
+    peak_flops, peak_bw = peaks["flops_per_s"], peaks["bytes_per_s"]
     costs = REGISTRY.kernel_costs()
     total = 0.0
     per_label = {}
@@ -764,9 +780,9 @@ def roofline_seconds(label_calls: dict,
         t = n * max(flops / peak_flops, bys / peak_bw)
         per_label[label] = round(t, 6)
         total += t
-    return {"seconds": round(total, 6), "per_label": per_label,
-            "peak_flops": peak_flops, "peak_bytes_per_s": peak_bw,
-            "platform": platform}
+    out.update(seconds=round(total, 6), per_label=per_label,
+               peak_flops=peak_flops, peak_bytes_per_s=peak_bw)
+    return out
 
 
 def compile_cache_snapshot() -> dict:

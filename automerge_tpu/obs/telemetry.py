@@ -23,7 +23,7 @@ lock, writers are never globally paused):
   the time-series view is bounded regardless of process lifetime.
 
 Memory bound: `stripes × (n_windows × live keys + histogram keys)`
-small dicts. Keys come from the code-defined category taxonomy
+small dicts. Keys come from the code-defined category catalogue
 (INTERNALS §11.3), not from peers, so the key population is bounded by
 the instrumentation, never by traffic. Gauges are a single small
 last-value-wins dict keyed (name, labels) under one lock — gauge

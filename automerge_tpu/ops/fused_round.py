@@ -73,14 +73,17 @@ def fused_mode() -> str:
     elsewhere. "lax" is the default off-chip rung because the Pallas
     interpreter pays a per-tile Python dispatch tax that would slow the
     cpu tier-1 suite; the interpret rung is exercised by the targeted
-    parity tests instead."""
+    parity tests instead. A backend that cannot be probed raises: the
+    rung is never guessed."""
     m = os.environ.get("AMTPU_FUSED_MODE", "")
     if m in _MODES:
         return m
     try:
         backend = jax.default_backend()
-    except Exception:  # pragma: no cover - backend probe failure
-        backend = "cpu"
+    except RuntimeError as exc:
+        raise RuntimeError(
+            "fused_mode: cannot probe the JAX backend to pick the scan "
+            f"rung ({exc}); set AMTPU_FUSED_MODE to choose one") from exc
     return "pallas" if backend == "tpu" else "lax"
 
 

@@ -35,7 +35,7 @@ def gather_spans(codes, spans, *, P: int):
     buffer of bucketed static length `P` — the device half of the
     incremental text pull: D changed spans ship d2h as a single transfer
     of O(edits) bytes instead of the whole O(doc) codes buffer (or D
-    separate RTT-bound fetches).
+    separate round-trip-bound fetches).
 
     `spans` is a packed (2, D) int32 matrix [starts, lens] (padding rows:
     len 0). Output element j belongs to the span whose cumulative-length

@@ -10,7 +10,7 @@ batches over whole documents.
 holds the fused Pallas variant: one kernel computes the segment-rank,
 segment-head, and visibility scans in a single HBM pass with SMEM carries
 (designed for bandwidth parity with XLA's fused scans; the on-chip A/B
-lives in profile_bench.py --pallas, see docs/MEASUREMENTS.md - and kept
+lives in profile_bench.py --pallas - and kept
 as the building block for the sharded long-sequence case,
 where the per-block carries become explicit ICI exchanges).
 """

@@ -224,18 +224,8 @@ def sharded_fused_scans(mesh, chain, has_value, n_elems, *, axis: str = "elem",
     ops/scan.py: the per-block carry becomes an explicit collective instead
     of XLA gathering the whole table for an unpartitionable scan.
     """
-    # version-tolerant import: jax >= 0.6 exposes jax.shard_map with a
-    # `check_vma` knob; 0.4.x has jax.experimental.shard_map with the
-    # same knob named `check_rep`. The baked-in toolchain here is 0.4.x,
-    # so the old spelling must keep working (it silently broke the
-    # sharded-carry parity tests for a round).
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
-    try:
-        from jax import shard_map
-        _check_kw = {"check_vma": False}
-    except ImportError:
-        from jax.experimental.shard_map import shard_map
-        _check_kw = {"check_rep": False}
 
     C = chain.shape[0]
     n_shards = mesh.shape[axis]
@@ -262,5 +252,5 @@ def sharded_fused_scans(mesh, chain, has_value, n_elems, *, axis: str = "elem",
                    in_specs=(P(axis), P(axis), P()),
                    out_specs=(P(axis), P(axis), P(axis)),
                    # pallas_call outputs carry no vma/replication info
-                   **_check_kw)
+                   check_vma=False)
     return fn(chain, has_value, jnp.asarray([n_elems], jnp.int32))

@@ -3,7 +3,7 @@
 The serving tier's scaling claim rests on one invariant: the commit
 path moves ZERO bytes between devices. The lanes make that structural
 (every lane program is single-device), and this module proves the
-stronger SPMD formulation the mesh design rests on (docs/SHARDING_r5.md):
+stronger SPMD formulation the mesh design rests on:
 the PR-7 stacked round kernels, lowered with every operand sharded over
 a doc-only mesh, compile to modules containing **no all-reduce /
 all-gather / all-to-all / collective-permute / reduce-scatter** — XLA's
@@ -48,7 +48,6 @@ def commit_path_collectives(mesh=None, docs_per_device: int = 2,
     zero-collective invariant holds). Shapes are small — the audit is
     about partitioning structure, not scale."""
     import jax
-    import jax.numpy as jnp
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     from ..ops import ingest as K
@@ -60,7 +59,9 @@ def commit_path_collectives(mesh=None, docs_per_device: int = 2,
     M, R, N, Kc, T, S = 64, 64, 256, 64, 64, 64
 
     def put(arr):
-        return jax.device_put(arr, shard)
+        # lowering needs shapes and shardings only — no device buffers,
+        # so the audit also compiles for a described (unattached) mesh
+        return jax.ShapeDtypeStruct(arr.shape, arr.dtype, sharding=shard)
 
     i32 = np.int32
     elem_tables = (put(np.zeros((D, cap), i32)),          # parent
@@ -178,7 +179,6 @@ def commit_path_collectives(mesh=None, docs_per_device: int = 2,
         in_shardings=(shard,) * 11, out_shardings=shard)
     out["fused_commit_round"] = count_collectives(
         fused_commit_fn, elem_tables + (put(desc), put(blob)))
-    del jnp
     return out
 
 

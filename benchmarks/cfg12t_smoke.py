@@ -23,12 +23,8 @@ import json
 import os
 import sys
 
-os.environ.setdefault("AMTPU_SKIP_PREFLIGHT", "1")
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
-from benchmarks.common import setup_jax_cache  # noqa: E402
-
-setup_jax_cache()
 
 N_DOCS = 24
 N_ROUNDS = 3
@@ -106,4 +102,7 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    from automerge_tpu._env import setup_compile_cache
+
+    setup_compile_cache()
     sys.exit(main())

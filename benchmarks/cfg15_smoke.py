@@ -19,12 +19,7 @@ INTERNALS §19). One process, three checks:
 
 import os
 
-os.environ.setdefault("AMTPU_SKIP_PREFLIGHT", "1")
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
-
-from benchmarks.common import setup_jax_cache  # noqa: E402
-
-setup_jax_cache()
 
 
 def main():
@@ -77,4 +72,7 @@ def main():
 
 
 if __name__ == "__main__":
+    from automerge_tpu._env import setup_compile_cache
+
+    setup_compile_cache()
     main()

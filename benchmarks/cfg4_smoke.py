@@ -21,19 +21,13 @@ benchmarks/run_all.trellis_changes) runs three ways:
 the PR-4 credibility rules (full JSON, git-sha-stamped, append-only).
 On cpu the DISPATCH-COUNT delta is the headline — cpu e2e is
 device-bound on the dev box and wall-clock A/Bs there are noise; the
-wall-clock payoff lands where dispatch overhead is a real link
-(docs/MEASUREMENTS.md cfg4 closure).
+wall-clock payoff lands where dispatch overhead is a real cost (a
+chip's host<->device round trip).
 """
 
 import json
 import os
 import sys
-
-os.environ.setdefault("AMTPU_SKIP_PREFLIGHT", "1")
-
-from benchmarks.common import setup_jax_cache  # noqa: E402
-
-setup_jax_cache()
 
 
 def _merge(saved: bytes, changes, flag: str):
@@ -162,4 +156,7 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    from automerge_tpu._env import setup_compile_cache
+
+    setup_compile_cache()
     main()

@@ -47,7 +47,7 @@ SLOS = [
     # plus the cold-planning microbench's own throughput floor. The
     # scaleup RATIO gets an absolute bar below, not a relative one: its
     # denominator (the per-object single-shard leg) swings with box
-    # conditions across sessions (docs/MEASUREMENTS.md ISSUE 12), so a
+    # conditions across sessions, so a
     # ratio-vs-prior rule would page on comparator weather
     ("cfg12_sharded", "text_population.aggregate_ops_per_sec",
      "min", 0.8),

@@ -3,7 +3,7 @@
 Round 4 ran ~800 ad-hoc soak sessions that found a real convergence bug
 (net-zero remote histories silently dropped by merge — fixed,
 tests/test_integration.py::TestNetZeroMerge); the runner itself was never
-committed (VERDICT r4 Next #7). This is that harness as a reproducible,
+committed. This is that harness as a reproducible,
 seeded tool, exceeding the reference's fixed-scenario suite
 (/root/reference/test/connection_test.js:17-65) by fuzzing at scale.
 
@@ -1721,4 +1721,7 @@ def main():
 
 
 if __name__ == "__main__":
+    from automerge_tpu._env import setup_compile_cache
+
+    setup_compile_cache()
     sys.exit(main())

@@ -483,7 +483,7 @@ def test_bench_restore_metrics_small_scale():
     assert rec["restore_snapshot_s"] > 0
     assert rec["restore_bundle_bytes"] > 0
     # no speed assertion at toy scale — the 1M-doc ratio is pinned by the
-    # bench record (docs/MEASUREMENTS.md); this pins shape + equivalence
+    # bench record (bench.py measure_restore); this pins shape + equivalence
 
 
 def test_grab_mid_mutation_serves_commit_boundary_snapshot():

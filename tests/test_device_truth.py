@@ -322,8 +322,7 @@ def test_metrics_snapshot_carries_device_truth():
 
 def test_compile_cache_state_is_observable_and_jax_free():
     state = _env.compile_cache_state()
-    assert set(state) >= {"dir", "enabled", "exists", "entries",
-                          "min_compile_time_secs"}
+    assert set(state) >= {"dir", "exists", "entries"}
     # overriding the env var is visible without touching jax
     state2 = _env.compile_cache_state(
         {"JAX_COMPILATION_CACHE_DIR": "/nonexistent-cache-dir"})
@@ -332,6 +331,58 @@ def test_compile_cache_state_is_observable_and_jax_free():
     snap = dt.compile_cache_snapshot()
     assert {"session_cache_hits", "session_cache_misses",
             "session_compiles"} <= set(snap)
+
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+_PLACEMENT_PROBE = (
+    "import sys; sys.path.insert(0, {repo!r}); "
+    "from automerge_tpu._env import setup_compile_cache; "
+    "import jax, jax.numpy as jnp; "
+    "print(setup_compile_cache()); "
+    "print(jax.config.jax_compilation_cache_dir); "
+    "jax.jit(lambda x: x * 3 + 1)(jnp.arange(7)).block_until_ready()")
+
+
+@pytest.mark.parametrize("env_set", [True, False],
+                         ids=["env-set", "env-unset"])
+def test_compile_cache_placement(tmp_path, env_set):
+    """`setup_compile_cache` is the one place the cache is set: with
+    JAX_COMPILATION_CACHE_DIR set the run writes its cache there; unset,
+    the cache is <repo>/.jax_cache whatever the working directory."""
+    import subprocess
+    import sys
+
+    from automerge_tpu._env import virtual_cpu_env
+    env = virtual_cpu_env(1)
+    env.pop("JAX_COMPILATION_CACHE_DIR", None)
+    want = os.path.join(REPO, ".jax_cache")
+    if env_set:
+        want = str(tmp_path / "x")
+        env["JAX_COMPILATION_CACHE_DIR"] = want
+        env["JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS"] = "0"
+    cwd = tmp_path / "elsewhere"
+    cwd.mkdir()
+    out = subprocess.run(
+        [sys.executable, "-c", _PLACEMENT_PROBE.format(repo=REPO)],
+        capture_output=True, text=True, env=env, cwd=cwd, timeout=120)
+    assert out.returncode == 0, out.stderr[-2000:]
+    assert out.stdout.split() == [want, want]
+    if env_set:
+        assert any(not n.endswith("-atime") for n in os.listdir(want))
+    assert not os.listdir(cwd)
+
+
+def test_peak_rates_keyed_by_device_kind():
+    v5e = dt.peak_rates("TPU v5 lite")
+    assert v5e == {"flops_per_s": 197e12, "bytes_per_s": 819e9}
+    with pytest.raises(ValueError, match="no published peak rates"):
+        dt.peak_rates("TPU v9 imaginary")
+
+
+def test_roofline_not_measured_on_cpu():
+    roof = dt.roofline_seconds({"merge_materialize_dense": 3})
+    assert roof["seconds"] is None and roof["per_label"] == {}
+    assert roof["platform"] == "cpu"
 
 
 # -- 6: overhead bounds ----------------------------------------------------
@@ -462,4 +513,4 @@ def test_cfg15_quick_record_asserts_steady_state():
     assert rec["bytes_staged_per_op"] > 0
     assert rec["peak_device_bytes"] > 0
     assert rec["prom_families_validated"] is True
-    assert rec["compile_cache"]["enabled"]
+    assert rec["compile_cache"]["dir"]

@@ -2,7 +2,7 @@
 
 Counting is link-independent: these bars gate identically on cpu and on
 chip, which is the point — an extra blocking sync per batch is invisible
-in cpu wall clock but costs a full WAN round trip (~70 ms) at deployment.
+in cpu wall clock but costs a host<->device round trip on the chip.
 Pinned here:
 
 - the write-behind interactive path (`am.change`) performs ZERO device
@@ -174,7 +174,7 @@ def _conflict_doc(n_actors=6, n_targets=40, **doc_attrs):
 def test_residual_round_is_one_sync():
     """The residual slow-register path: ONE blocking d2h (the packed
     slow_info fetch) per round, independent of how many registers went
-    slow — the one-RTT contract the WAN tunnel's cfg5b bound rests on."""
+    slow — the one-round-trip contract cfg5b's bound rests on."""
     doc, batch = _conflict_doc()
     snap = dict(doc._acct)
     doc.commit_prepared(doc.prepare_batch(batch))

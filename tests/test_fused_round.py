@@ -77,6 +77,19 @@ def test_fused_mode_ladder(monkeypatch):
     assert F.fused_rounds_enabled()              # default ON
 
 
+def test_fused_mode_raises_when_backend_probe_fails(monkeypatch):
+    """The rung is never guessed: a backend that cannot be probed
+    raises instead of silently running the lax rung."""
+    from automerge_tpu.ops import fused_round as F
+
+    def broken():
+        raise RuntimeError("Unable to initialize backend 'tpu'")
+    monkeypatch.delenv("AMTPU_FUSED_MODE", raising=False)
+    monkeypatch.setattr(F.jax, "default_backend", broken)
+    with pytest.raises(RuntimeError, match="cannot probe"):
+        F.fused_mode()
+
+
 # ---------------------------------------------------------------------------
 # direct kernel parity: the fused core vs the XLA comparator
 # ---------------------------------------------------------------------------

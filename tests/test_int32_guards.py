@@ -1,4 +1,4 @@
-"""int32-envelope capacity guards (ISSUE 4 satellite / VERDICT r5 #3).
+"""int32-envelope capacity guards (ISSUE 4 satellite).
 
 The device tier packs elemId keys as (actor_rank << 32 | ctr) int64 and
 stores every column int32; actor ranks stand in for the reference's
