@@ -28,13 +28,17 @@ wraparound.
 Enable via ``AMTPU_TRACE=1`` in the environment, `obs.enable()`, or the
 scoped ``with obs.tracing(): ...``. Export with `obs.write_trace(path)`
 (Chrome trace-event JSON — load at https://ui.perfetto.dev) and read
-aggregates with `obs.metrics_snapshot()`.
+aggregates with `obs.metrics_snapshot()`. `obs.anchor_profiler()` writes
+the ring clock into a running ``jax.profiler`` trace, so ring records can
+be placed on the device trace's timeline.
 
 Category catalogue (full schema in docs/INTERNALS.md §11):
 
   plan    host planning: prepare_batch / admission / wire decode
   commit  commit_prepared (args carry n_rounds + dispatch/sync delta)
-  device  dispatch/sync accounting (labeled kernel counters), waits
+  device  dispatch/sync accounting (labeled kernel counters), waits;
+          `compile`: one jitted-kernel compile or persistent-cache load
+          (args kernel = <label>/<variant>)
   ring    PipelinedIngestor slot lifecycle (plan/commit spans,
           fallback/serial/abort events, gen + slot tags)
   pull    text materialization pulls (mode + byte counts)
@@ -45,8 +49,22 @@ Category catalogue (full schema in docs/INTERNALS.md §11):
           evict_pressure + dead-peer evict_peer) / releases
   sync    hub snapshot bootstrap (snapshot_capture / serve_cached —
           the join-storm coalescing ratio)
-  svc     service tier: tick spans, shed / defer / suspect / evict /
-          join / rejoin / protocol_error events (INTERNALS §13)
+  svc     service tier (INTERNALS §13): the `tick` span and its
+          children, which cover it — `admit` (the admission loop),
+          `deliver` (one per (room, doc) group; args room / n_changes /
+          n_ops / fast, fast = the gate's binary wire fast lane took
+          it), `sessions` (retransmit + health passes, evictions),
+          `lag` (bounds + lag probe), `mesh` (residency paging, when
+          on), `flush` (every room's deferred hub flush); one
+          `inbox_wait` per admitted change message, from its enqueue
+          to its admission (args room / doc / tick); shed / defer /
+          suspect / evict / join / rejoin / protocol_error events
+  backend `apply`: Backend.apply_changes inside one doc apply
+  frontend `patch`: Frontend.apply_patch of that apply
+  hub     `flush`: one room hub's change extraction + frame encode +
+          sends (args room / peers / frames / bytes)
+  host    `gc`: one garbage collection (args generation / collected),
+          from a gc.callbacks hook registered only while tracing is on
   ckpt    checkpoint writer (grab spans, conflicts, degrades)
   bench   harness-side regions (stream reps, explicit device waits)
   lineage per-change provenance hops (obs/lineage.py, INTERNALS §18):
@@ -59,6 +77,7 @@ Category catalogue (full schema in docs/INTERNALS.md §11):
 
 from __future__ import annotations
 
+import gc
 import os
 import threading
 import time
@@ -111,12 +130,59 @@ def enable(capacity: Optional[int] = None) -> FlightRecorder:
     elif _telemetry is None:
         _telemetry = Telemetry()
     ENABLED = True
+    if _on_gc not in gc.callbacks:
+        gc.callbacks.append(_on_gc)
     return _recorder
 
 
 def disable():
     global ENABLED
+    _emit_gc()
     ENABLED = False
+    if _on_gc in gc.callbacks:
+        gc.callbacks.remove(_on_gc)
+
+
+_gc_t0 = 0
+#: finished collections not yet in the ring: (t0, t1, generation,
+#: collected). A collection can interrupt its thread inside the ring's or
+#: the telemetry's lock, so the hook only queues; the next span(),
+#: event(), snapshot() or disable() emits.
+_gc_done: list = []
+
+
+def _on_gc(phase: str, info: dict):
+    """The ``gc.callbacks`` hook (registered by enable() only): one
+    ``host/gc`` span per collection. Collections never overlap, so one
+    start instant suffices."""
+    global _gc_t0
+    if phase == "start":
+        _gc_t0 = now() if ENABLED else 0
+    elif _gc_t0:
+        _gc_done.append((_gc_t0, now(), info["generation"],
+                         info["collected"]))
+        _gc_t0 = 0
+
+
+def _emit_gc():
+    while _gc_done:
+        try:
+            t0, t1, generation, collected = _gc_done.pop()
+        except IndexError:      # another thread took the last one
+            return
+        _record_span("host", "gc", t0,
+                     {"generation": generation, "collected": collected}, t1)
+
+
+def anchor_profiler():
+    """Write one ``obs.clock`` ``jax.profiler.TraceAnnotation`` into the
+    running profiler trace, carrying this clock's reading at the
+    annotation's entry as its ``perf_ns`` stat. Two anchors (one after
+    ``start_trace``, one before ``stop_trace``) map ring timestamps onto
+    the trace's timeline linearly. Imports jax only when called."""
+    from jax.profiler import TraceAnnotation
+    with TraceAnnotation("obs.clock", perf_ns=now()):
+        pass
 
 
 @contextmanager
@@ -143,6 +209,12 @@ def span(cat: str, name: str, t0_ns: int, args: Optional[dict] = None,
     """Record a completed span started at `t0_ns` (from `obs.now()`).
     A zero `t0_ns` (tracing was off when the region started) is dropped —
     a half-observed region must not fabricate a duration."""
+    if _gc_done:
+        _emit_gc()
+    _record_span(cat, name, t0_ns, args, t1_ns)
+
+
+def _record_span(cat, name, t0_ns, args, t1_ns):
     rec = _recorder
     if rec is None or not t0_ns:
         return
@@ -156,6 +228,8 @@ def span(cat: str, name: str, t0_ns: int, args: Optional[dict] = None,
 
 def event(cat: str, name: str, args: Optional[dict] = None, n: int = 1):
     """Record an instant event AND bump its wrap-proof counter."""
+    if _gc_done:
+        _emit_gc()
     rec = _recorder
     if rec is None:
         return
@@ -198,6 +272,7 @@ def span_ctx(cat: str, name: str, args: Optional[dict] = None):
 def snapshot(since_ns: int = 0) -> list:
     """All retained records (see recorder.snapshot); [] when never
     enabled."""
+    _emit_gc()
     return [] if _recorder is None else _recorder.snapshot(since_ns)
 
 

@@ -34,6 +34,8 @@ HLO:
   variant) are attributed to their differing shape signatures
   (`recompile_report()`); :class:`steady_state` gives benches an
   in-run ``recompiles == 0`` assertion — the cfg15 acceptance bar.
+  While obs tracing is on, each compile is also a ``device/compile``
+  ring span (args: kernel) over its wall time.
 - **Footprint metering.** `CausalDeviceDoc.device_footprint()` computes
   live table bytes from dtype x shape over the engine tables (9 for
   text, 5 for map) — parity with the live ``jax.Array`` buffer sizes is
@@ -289,6 +291,11 @@ class DeviceTruthRegistry:
     def _record_compile(self, handle: KernelHandle, wall_ns: int,
                         args: tuple, kwargs: dict):
         # called under _LOCK from KernelHandle.__call__
+        from .. import obs
+        if obs.ENABLED:
+            end = time.perf_counter_ns()
+            obs.span("device", "compile", end - wall_ns, t1_ns=end,
+                     args={"kernel": f"{handle.label}/{handle.variant}"})
         sig = _sig_of(args, kwargs)
         self.compiles_total += 1
         self.compile_ns_total += wall_ns

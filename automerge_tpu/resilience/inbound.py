@@ -96,6 +96,7 @@ class InboundGate:
         self._n_parked = 0                # total across all docs
         self._busy: set = set()           # re-entrancy guard (doc ids)
         self.stats = {"delivered": 0, "applied_ops": 0,
+                      "wire_fast": 0,     # deliveries the fast lane took
                       "parked_rejected": 0,
                       "global_evicted": 0,
                       "peak_parked": 0}      # per-doc quarantine stats
@@ -163,10 +164,7 @@ class InboundGate:
                 try:
                     doc = self._apply(doc_id, delivery)
                     self.stats["delivered"] += delivery.n_changes
-                    if obs.ENABLED:
-                        obs.event("gate", "wire_fast",
-                                  args={"doc": doc_id,
-                                        "n_ops": delivery.n_ops})
+                    self.stats["wire_fast"] += 1
                     return doc
                 except ProtocolError:
                     # backend rejection: its failure-atomic restore ran,

@@ -62,6 +62,26 @@ from .budget import ServiceConfig, TenantBudget, approx_msg_bytes
 LIVE, SUSPECT, DEAD = "live", "suspect", "dead"
 
 
+def _lap(name: str, t0: int) -> int:
+    """Emit the ``svc/<name>`` child span of the tick from ``t0`` to now
+    and return now, the next child's start. Call only behind
+    ``obs.ENABLED``."""
+    t1 = obs.now()
+    obs.span("svc", name, t0, t1_ns=t1)
+    return t1
+
+
+def _deliver_span(t0: int, room_id: str, changes, frames, n_ops: int,
+                  fast: bool):
+    """The ``svc/deliver`` span of one (room, doc) group: changes
+    delivered, ops applied, and whether the gate's binary wire fast lane
+    took it. Call only behind ``obs.ENABLED``."""
+    obs.span("svc", "deliver", t0, args={
+        "room": room_id,
+        "n_changes": len(changes) + sum(f.n_changes for f, _ in frames),
+        "n_ops": n_ops, "fast": fast})
+
+
 class Room:
     """One doc group's serving shard: DocSet + hub + bounded gate.
 
@@ -91,7 +111,7 @@ class Room:
             self.doc_set, capacity=config.quarantine_capacity,
             global_capacity=config.quarantine_global_capacity)
         self.doc_set._inbound_gate = self.gate   # the one shared gate
-        self.hub = SyncHub(self.doc_set)
+        self.hub = SyncHub(self.doc_set, label=room_id)
         self.doc_set._sync_hub = self.hub        # Connection-compat cache
         self.hub.open()
         self.tenants: set = set()
@@ -112,7 +132,9 @@ class TenantSession:
         self.room_id = room_id
         self.budget = budget
         self.channel = None            # installed by SyncService.connect
-        self.inbox: deque = deque()    # (msg, nbytes, nops)
+        # (msg, nbytes, nops, enqueue instant on the obs clock: 0 unless
+        # tracing was on when the message arrived)
+        self.inbox: deque = deque()
         self.inbox_bytes = 0
         self.last_inbound_tick = svc._tick_no
         self.state = LIVE
@@ -174,7 +196,8 @@ class TenantSession:
             from ..engine.wire_format import as_frame
             nops += as_frame(wire).n_ops
         nbytes = approx_msg_bytes(msg)
-        self.inbox.append((msg, nbytes, max(1, nops)))
+        self.inbox.append((msg, nbytes, max(1, nops),
+                           obs.now() if obs.ENABLED else 0))
         self.inbox_bytes += nbytes
         svc_stats = self._svc.stats
         if len(self.inbox) > svc_stats["peak_inbox"]:
@@ -372,7 +395,13 @@ class SyncService:
     def tick(self):
         """One scheduler round: budgeted cross-tenant admission (grouped
         per doc), retransmission, peer-health escalation, evictions, and
-        one deferred hub flush per room."""
+        one deferred hub flush per room.
+
+        With tracing on, the ``svc/tick`` span is covered by its
+        children, in order: ``svc/admit``, one ``svc/deliver`` per
+        (room, doc) group, ``svc/sessions``, ``svc/flush``, ``svc/mesh``
+        (residency tier only) and ``svc/lag`` — a fixed number of
+        records per tick for the passes over every session."""
         t0 = obs.now() if obs.ENABLED else 0
         t_start = time.perf_counter()
         self._tick_no += 1
@@ -428,6 +457,8 @@ class SyncService:
                     obs.event("svc", "shed",
                               args={"msgs": shed, "tick": self._tick_no},
                               n=shed)
+            if obs.ENABLED:
+                _lap("admit", t0)
             # grouped admission: ONE gate delivery (one backend apply /
             # columnar decode) per (room, doc) for the whole tick —
             # executed under the room's shard-lane device context when
@@ -438,6 +469,7 @@ class SyncService:
             # flush stack — the one-flush-per-room amortization is
             # preserved at the barrier
             self._deliver_groups(groups)
+            t = obs.now() if obs.ENABLED else 0
             # retransmission (may declare peers dead via on_dead)
             for sess in list(self._tenants.values()):
                 if not sess.pending_dead:
@@ -446,7 +478,11 @@ class SyncService:
             for sess in [s for s in list(self._tenants.values())
                          if s.pending_dead]:
                 self.evict(sess.tenant_id, sess.pending_dead)
-        self._track_bounds()
+            if obs.ENABLED:
+                t = _lap("sessions", t)
+        # the stack's exit ran every touched room's deferred hub flush
+        if obs.ENABLED:
+            t = _lap("flush", t)
         if self._doc_mesh is not None:
             # the residency tier's tick-loop paging hooks: drain the
             # bulk-mesh backlog through the paging gate (deliver_round
@@ -457,9 +493,14 @@ class SyncService:
             for deliveries in backlog:
                 self._doc_mesh.deliver_round(deliveries)
             self._residency.tick()
+            if obs.ENABLED:
+                t = _lap("mesh", t)
+        self._track_bounds()
         if cfg.lag_probe_ticks \
                 and self._tick_no % cfg.lag_probe_ticks == 0:
             self.probe_lag()
+        if obs.ENABLED:
+            _lap("lag", t)
         self.stats["ticks"] += 1
         dt_ms = (time.perf_counter() - t_start) * 1e3
         self._tick_ms.append(dt_ms)
@@ -557,10 +598,12 @@ class SyncService:
     def _deliver_one_group(self, key, room, payload):
         """One (room, doc) group through the gate — the sequential leg,
         kept verbatim from the pre-parallel tick."""
-        (_room_id, doc_id) = key
+        (room_id, doc_id) = key
         (changes, senders, frames) = payload
         lane = room.lane
-        ops0 = room.gate.stats["applied_ops"]
+        t0 = obs.now() if obs.ENABLED else 0
+        gate_stats = room.gate.stats
+        ops0, fast0 = gate_stats["applied_ops"], gate_stats["wire_fast"]
         try:
             with (lane.device_ctx() if lane is not None
                   else nullcontext()):
@@ -587,14 +630,16 @@ class SyncService:
                 obs.event("svc", "reject",
                           args={"doc": doc_id,
                                 "error": str(exc)[:120]})
+        # the gate's applied-ops delta, NOT the delivered op count: a
+        # premature change that parks costs this lane nothing (it counts
+        # on the tick that drains it), so the per-lane load series the
+        # rebalance policy reads stays honest — measured even on the
+        # salvage path, where valid changes still applied
+        n_ops = gate_stats["applied_ops"] - ops0
+        if obs.ENABLED:
+            _deliver_span(t0, room_id, changes, frames, n_ops,
+                          gate_stats["wire_fast"] > fast0)
         if lane is not None:
-            # the gate's applied-ops delta, NOT the delivered op
-            # count: a premature change that parks costs this
-            # lane nothing (it counts on the tick that drains
-            # it), so the per-lane load series the rebalance
-            # policy reads stays honest — measured even on the
-            # salvage path, where valid changes still applied
-            n_ops = room.gate.stats["applied_ops"] - ops0
             if n_ops:
                 lane.stats["admitted_ops"] += n_ops
                 self.telemetry.observe_count(
@@ -610,8 +655,10 @@ class SyncService:
         no lost updates on the shared stats dicts). The worker thread
         already runs inside the lane's device context."""
         fold = {"lane_ops": {}, "rejects": []}
-        for (_room_id, doc_id), room, (changes, senders, frames) in items:
-            ops0 = room.gate.stats["applied_ops"]
+        for (room_id, doc_id), room, (changes, senders, frames) in items:
+            t0 = obs.now() if obs.ENABLED else 0
+            gate_stats = room.gate.stats
+            ops0, fast0 = gate_stats["applied_ops"], gate_stats["wire_fast"]
             try:
                 if frames:
                     room.gate.deliver_wire(
@@ -622,7 +669,10 @@ class SyncService:
                                       sender=senders)
             except ProtocolError as exc:
                 fold["rejects"].append((doc_id, str(exc)[:120]))
-            n_ops = room.gate.stats["applied_ops"] - ops0
+            n_ops = gate_stats["applied_ops"] - ops0
+            if obs.ENABLED:
+                _deliver_span(t0, room_id, changes, frames, n_ops,
+                              gate_stats["wire_fast"] > fast0)
             if n_ops:
                 idx = room.lane.index
                 fold["lane_ops"][idx] = \
@@ -672,7 +722,7 @@ class SyncService:
         if not all(t.done() for t in tasks):
             pending = []
             for sess in self._tenants.values():
-                for msg, _nb, _no in sess.inbox:
+                for msg, _nb, _no, _t in sess.inbox:
                     wire = msg.get("wire")
                     if isinstance(wire, WireFrame) \
                             and getattr(wire, "_batch", None) is None:
@@ -719,7 +769,7 @@ class SyncService:
         ops_left, bytes_left = b.ops_per_tick, b.bytes_per_tick
         admitted = 0
         while sess.inbox:
-            msg, nbytes, nops = sess.inbox[0]
+            msg, nbytes, nops, t_enq = sess.inbox[0]
             if admitted and (nops > ops_left or nbytes > bytes_left):
                 # budget exhausted: the remainder defers to later ticks.
                 # (The FIRST message of a visit always admits, so an
@@ -746,6 +796,15 @@ class SyncService:
                 break
             sess.inbox.popleft()
             sess.inbox_bytes -= nbytes
+            if t_enq and obs.ENABLED and (
+                    msg.get("changes") or msg.get("wire") is not None):
+                # the message's wait in the inbox, enqueue to admission
+                # (deferred and shed ticks included); room + tick join it
+                # to the same tick's svc/deliver and hub/flush spans
+                obs.span("svc", "inbox_wait", t_enq,
+                         args={"room": sess.room_id,
+                               "doc": msg.get("docId"),
+                               "tick": self._tick_no})
             self._admit_msg(sess, msg, groups)
             ops_left -= nops
             bytes_left -= nbytes

@@ -9,6 +9,7 @@ from __future__ import annotations
 
 from ..backend import default as Backend
 from .. import frontend as Frontend
+from .. import obs
 
 
 class DocSet:
@@ -45,14 +46,22 @@ class DocSet:
         inbound gate uses this to separate backend rejection (state
         untouched, wrapped as ProtocolError) from exceptions raised by
         change handlers after the commit (which must propagate as-is:
-        the document did change)."""
+        the document did change). With tracing on, the two steps are the
+        ``backend/apply`` and ``frontend/patch`` spans."""
         doc = self._docs.get(doc_id)
         if doc is None:
             doc = Frontend.init({"backend": Backend.Backend})
         old_state = Frontend.get_backend_state(doc)
+        t0 = obs.now() if obs.ENABLED else 0
         new_state, patch = Backend.apply_changes(old_state, changes)
         patch["state"] = new_state
-        return Frontend.apply_patch(doc, patch)
+        t1 = obs.now() if obs.ENABLED else 0
+        if obs.ENABLED:
+            obs.span("backend", "apply", t0, t1_ns=t1)
+        doc = Frontend.apply_patch(doc, patch)
+        if obs.ENABLED:
+            obs.span("frontend", "patch", t1)
+        return doc
 
     def deliver(self, doc_id: str, changes):
         """Validated + quarantined inbound application (the network path).

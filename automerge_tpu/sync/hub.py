@@ -64,8 +64,10 @@ class SyncHub:
     except ValueError:   # malformed env must not break `import automerge_tpu`
         snapshot_min_changes = 64
 
-    def __init__(self, doc_set):
+    def __init__(self, doc_set, label=None):
         self._doc_set = doc_set
+        #: the name ``hub/flush`` spans carry (the service's room id)
+        self.label = label
         self._peers: dict = {}
         self._matrix = ClockMatrix()
         self._advertised: dict = {}   # (peer, doc) -> clock last advertised
@@ -234,15 +236,18 @@ class SyncHub:
         ``split_outgoing`` per (doc, clock) group mints one
         ``AMTPUWIRE1`` frame serving every peer of the group — and the
         channel layer retransmits those exact bytes, never re-encoding
-        (INTERNALS §17)."""
+        (INTERNALS §17). With tracing on, one ``hub/flush`` span: messages
+        sent (``peers``), frames encoded and their bytes."""
         if self._defer_depth:
             self._flush_wanted = True
             return
+        t0 = obs.now() if obs.ENABLED else 0
         from ..engine.wire_format import split_outgoing, wire_binary_enabled
         binary = wire_binary_enabled()
         extracted: dict = {}
         encoded: dict = {}
         contexts: dict = {}   # same (doc, clock) key -> trace context
+        sent = 0
         for peer_id, doc_id in self._matrix.pending():
             if peer_id not in self._peers:
                 continue
@@ -328,6 +333,12 @@ class SyncHub:
                             if tail_ctx:
                                 msg["trace"] = tail_ctx
             self._peers[peer_id].send_msg(msg)
+            sent += 1
+        if obs.ENABLED:
+            frames = [f for _prefix, f in encoded.values() if f is not None]
+            obs.span("hub", "flush", t0, args={
+                "room": self.label, "peers": sent, "frames": len(frames),
+                "bytes": sum(f.nbytes for f in frames)})
 
     def _doc_checkpoint(self, doc_id: str, state):
         """(base64 bundle, tail changes) for a doc, cached per doc and

@@ -1,5 +1,8 @@
+import gc
 import os
 import sys
+
+import pytest
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 
@@ -16,3 +19,15 @@ if os.environ.get("AUTOMERGE_TPU_TESTS_ON_TPU") != "1":
     os.environ["JAX_PLATFORMS"] = _env["JAX_PLATFORMS"]
     os.environ["XLA_FLAGS"] = _env["XLA_FLAGS"]
 setup_compile_cache()
+
+
+@pytest.fixture
+def collector_paused():
+    """No automatic garbage collection during the test. With obs tracing
+    on, every collection is a ``host/gc`` ring record, which a test that
+    counts the ring's records exactly must not meet."""
+    was = gc.isenabled()
+    gc.disable()
+    yield
+    if was:
+        gc.enable()

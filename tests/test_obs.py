@@ -134,7 +134,7 @@ def test_ring_wraparound_keeps_newest():
     assert rec.n_emitted == 100 and rec.n_retained == 16
 
 
-def test_counters_exact_across_wraparound():
+def test_counters_exact_across_wraparound(collector_paused):
     """metrics_snapshot counters aggregate outside the ring: emitting
     far more events than capacity loses ring records, never counts."""
     with obs.tracing(capacity=16):
@@ -146,7 +146,7 @@ def test_counters_exact_across_wraparound():
     assert snap["retained"] < snap["emitted"] == 500
 
 
-def test_concurrent_writers_no_torn_records():
+def test_concurrent_writers_no_torn_records(collector_paused):
     """Writers on many threads (beyond the stripe count, so stripes are
     shared) emit concurrently; every snapshotted record is whole and
     attributed to exactly one writer, and nothing is lost below

@@ -169,7 +169,8 @@ class TestTelemetryStore:
 
 
 class TestWraparoundExactness:
-    def test_span_totals_exact_while_ring_view_diverges(self):
+    def test_span_totals_exact_while_ring_view_diverges(
+            self, collector_paused):
         """Force trace-ring wraparound: the telemetry-backed spans in
         metrics_snapshot stay exact; the retained-record derivation
         (the pre-ISSUE-9 source) visibly loses history."""
