@@ -50,6 +50,7 @@ from typing import Optional
 import numpy as np
 
 from .._common import ROOT_ID, make_elem_id, transitive_deps
+from .. import obs
 from ..resilience.validation import prevalidated, validate_changes
 from . import facade as _oracle
 from .facade import BackendState as _OracleState
@@ -159,9 +160,12 @@ class _TextOverlay:
                                     # discards the overlay)
 
     @classmethod
-    def build(cls, doc) -> "_TextOverlay":
-        """One positions+mirrors read of the CURRENT device state (the
-        only device interaction the overlay ever does)."""
+    def build(cls, wrapper: "_TextObj") -> "_TextOverlay":
+        """One positions read of the CURRENT device state (the only
+        device interaction the overlay ever does). Visibility is the
+        diff baseline's while nothing changed since it was taken, else
+        the element tables' mirror."""
+        doc = wrapper.doc
         n = doc.n_elems
         if n == 0:
             return cls(np.empty(0, np.int64), np.empty(0, bool))
@@ -169,10 +173,14 @@ class _TextOverlay:
         pos = np.asarray(doc._positions()[1:])
         order_slot = np.empty(n, np.int64)
         order_slot[pos] = np.arange(1, n + 1)
-        h = doc._mirrors()
+        dirty = doc.dirty_slots()
+        if wrapper.prev_n == n and dirty is not None and not len(dirty):
+            has_value = wrapper.prev_vis
+        else:
+            has_value = doc._mirrors()["has_value"]
         actor, ctr = doc.index.slot_to_key(order_slot)
         order = pack_keys(actor.astype(np.int64), ctr.astype(np.int64))
-        vis = np.array(h["has_value"], bool)[order_slot]
+        vis = np.array(has_value, bool)[order_slot]
         return cls(order, vis)
 
     def pos_of(self, packed: int) -> int:
@@ -221,7 +229,9 @@ class _TextObj:
                           o["counter"]) for o in ops)
                 for s, ops in doc.conflicts.items() if ops}
 
-    def snapshot(self):
+    def snapshot(self, conf: Optional[dict] = None):
+        """Take the diff baseline from the whole state (one mirror fetch;
+        `conf`, when given, is the current `conflict_sig()`)."""
         doc = self.doc
         n = doc.n_elems
         h = doc._mirrors() if n else {"has_value": np.zeros(1, bool),
@@ -229,7 +239,56 @@ class _TextObj:
         self.prev_n = n
         self.prev_vis = np.array(h["has_value"][: n + 1], bool)
         self.prev_value = np.array(h["value"][: n + 1], np.int32)
-        self.prev_conf = self.conflict_sig()
+        self.prev_conf = self.conflict_sig() if conf is None else conf
+        doc.clear_dirty()
+
+    def touched(self) -> tuple:
+        """(slots, rows): the slots whose patch entry may differ from the
+        baseline, in slot order — those inserted since (above `prev_n`)
+        and those a set, del or inc wrote, the only slots whose conflicts
+        the engine changes — and their rows from the engine. The slots
+        are None when they are more than the engine gathers in one
+        materialization, the rows None when the whole state has to be
+        read."""
+        from ..engine.text_doc import TOUCH_K
+        doc = self.doc
+        n, old_n = doc.n_elems, self.prev_n
+        dirty = doc.dirty_slots()
+        if dirty is None or not 0 <= n - old_n <= TOUCH_K:
+            return None, None
+        slots = np.unique(np.concatenate([np.arange(old_n + 1, n + 1),
+                                          dirty]))
+        if len(slots) > TOUCH_K:
+            return None, None
+        return slots, doc.touched_rows(slots)
+
+    def rebase(self):
+        """Move the baseline to the current state without emitting diffs:
+        from the touched slots' rows when they fit, else the whole state."""
+        conf = self.conflict_sig()
+        slots, rows = self.touched()
+        if rows is None:
+            self.snapshot(conf)
+        else:
+            self.advance(slots, rows, conf)
+
+    def advance(self, slots: np.ndarray, rows: np.ndarray, conf: dict):
+        """Move the baseline to the current state from the rows of every
+        slot `touched` named: their visibility and value are written in
+        place, the arrays grown by doubling."""
+        n = self.doc.n_elems
+        if len(self.prev_vis) < n + 1:
+            size = max(n + 1, 2 * len(self.prev_vis))
+            grown_vis = np.zeros(size, bool)
+            grown_val = np.zeros(size, np.int32)
+            grown_vis[: self.prev_n + 1] = self.prev_vis[: self.prev_n + 1]
+            grown_val[: self.prev_n + 1] = self.prev_value[: self.prev_n + 1]
+            self.prev_vis, self.prev_value = grown_vis, grown_val
+        self.prev_vis[slots] = rows[2].astype(bool)
+        self.prev_value[slots] = rows[3]
+        self.prev_n = n
+        self.prev_conf = conf
+        self.doc.clear_dirty()
 
 
 class _MapOverlay:
@@ -590,7 +649,7 @@ class _DeviceCore:
                 return None
 
         if wrapper.ov is None:
-            wrapper.ov = _TextOverlay.build(doc)
+            wrapper.ov = _TextOverlay.build(wrapper)
         ov = wrapper.ov
         plan = self._fast_plan(kind_, payload, ov, doc)
         if plan is None:
@@ -912,7 +971,7 @@ class _DeviceCore:
         for oid in touched:
             w = self.root if oid == ROOT_ID else self.objects.get(oid)
             if isinstance(w, _TextObj):
-                w.snapshot()
+                w.rebase()
             elif isinstance(w, _MapObj):
                 w.prev = w.current()
             if w is not None:
@@ -1304,14 +1363,97 @@ class _DeviceCore:
 
     def _text_diffs(self, obj_id: str, tobj: _TextObj, path, out: list,
                     rebuild: bool = False):
+        """A text/list object's net diffs since its baseline, which then
+        moves to the current state. A round that touched at most TOUCH_K
+        slots emits from those slots' rows alone (`_touched_text_diffs`);
+        a larger round, a rebuild and a failed round read the whole state
+        (`_full_text_diffs`). The two give identical diffs."""
+        t0 = obs.now() if obs.ENABLED else 0
         doc = tobj.doc
         n = doc.n_elems
+        conf = tobj.conflict_sig()
+        slots, rows = (None, None) if rebuild else tobj.touched()
         if n == 0:
             if tobj.max_elem and (rebuild or tobj.prev_n != n):
                 out.append({"action": "maxElem", "obj": obj_id,
                             "type": tobj.kind, "value": tobj.max_elem,
                             "path": path})
-            return
+            tobj.snapshot(conf)
+        elif rows is not None:
+            self._touched_text_diffs(obj_id, tobj, path, out, slots, rows,
+                                     conf)
+        else:
+            self._full_text_diffs(obj_id, tobj, path, out, rebuild, conf)
+            tobj.snapshot(conf)
+        if t0:
+            obs.span("backend", "diff", t0, args={
+                "mode": "touched" if rows is not None else "full",
+                "k": n if slots is None else len(slots)})
+
+    def _touched_text_diffs(self, obj_id: str, tobj: _TextObj, path,
+                            out: list, slots: np.ndarray, rows: np.ndarray,
+                            conf: dict):
+        """Net diffs from the touched slots' rows, O(k): every slot whose
+        visibility, value or conflicts can have changed is among them, so
+        an element's old index is its new one less the touched elements
+        before it that appeared, plus those that vanished."""
+        doc = tobj.doc
+        n, old_n = doc.n_elems, tobj.prev_n
+        order = np.argsort(rows[0], kind="stable")   # list order
+        slot = slots[order]
+        new_rank = rows[1][order].astype(np.int64)
+        vis = rows[2][order].astype(bool)
+        val = rows[3][order]
+        actor, ctr = rows[4][order].tolist(), rows[5][order].tolist()
+        old = slot <= old_n
+        o_vis = np.zeros(len(slot), bool)
+        o_vis[old] = tobj.prev_vis[slot[old]]
+        o_val = np.zeros(len(slot), np.int32)
+        o_val[old] = tobj.prev_value[slot[old]]
+        flip = vis.astype(np.int64) - o_vis
+        old_rank = new_rank - (np.cumsum(flip) - flip)
+        old_conf = tobj.prev_conf
+        typ = tobj.kind
+        # same order as the full path: removes by descending old index,
+        # inserts by ascending final index, then sets
+        for p in np.flatnonzero(o_vis & ~vis)[::-1]:
+            out.append({"action": "remove", "obj": obj_id, "type": typ,
+                        "index": int(old_rank[p]), "path": path})
+        ins = np.flatnonzero(~o_vis & vis)
+        at = doc.actor_table
+        for p in ins:
+            s = int(slot[p])
+            diff = {"action": "insert", "obj": obj_id, "type": typ,
+                    "index": int(new_rank[p]),
+                    "elemId": f"{at[actor[p]]}:{ctr[p]}",
+                    "path": path}
+            diff.update(self._decode_text(tobj, int(val[p])))
+            cf = self._text_conflicts(tobj, s)
+            if cf:
+                diff["conflicts"] = cf
+            out.append(diff)
+        for p in np.flatnonzero(o_vis & vis):
+            s = int(slot[p])
+            if val[p] == o_val[p] and conf.get(s) == old_conf.get(s):
+                continue
+            diff = {"action": "set", "obj": obj_id, "type": typ,
+                    "index": int(new_rank[p]), "path": path}
+            diff.update(self._decode_text(tobj, int(val[p])))
+            cf = self._text_conflicts(tobj, s)
+            if cf:
+                diff["conflicts"] = cf
+            out.append(diff)
+        if tobj.max_elem and (ins.size or old_n != n):
+            out.append({"action": "maxElem", "obj": obj_id, "type": typ,
+                        "value": tobj.max_elem, "path": path})
+        tobj.advance(slots, rows, conf)
+
+    def _full_text_diffs(self, obj_id: str, tobj: _TextObj, path, out: list,
+                         rebuild: bool, conf: dict):
+        """Net diffs from the whole state: the position vector and the
+        element tables, O(n)."""
+        doc = tobj.doc
+        n = doc.n_elems
         pos = doc._positions()               # RGA position per slot, len n+1
         order = np.empty(n, np.int64)
         order[np.asarray(pos[1:])] = np.arange(1, n + 1)  # slots in list order
@@ -1324,7 +1466,6 @@ class _DeviceCore:
         old_val = np.zeros(n + 1, np.int32)
         if not rebuild:
             old_val[: old_n + 1] = tobj.prev_value[: old_n + 1]
-        conf = tobj.conflict_sig()
         old_conf = {} if rebuild else tobj.prev_conf
 
         o_vis = old_vis[order]
@@ -1428,7 +1569,6 @@ class _DeviceCore:
         if isinstance(wrapper, _TextObj):
             self._text_diffs(oid, wrapper, paths.get(oid), out,
                              rebuild=rebuild)
-            wrapper.snapshot()
         else:
             self._map_diffs(oid, wrapper, paths.get(oid), out,
                             rebuild=rebuild)

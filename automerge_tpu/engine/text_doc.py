@@ -55,6 +55,12 @@ from .segments import SegmentMirror
 
 logger = logging.getLogger("automerge_tpu.engine")
 
+#: Slots one position materialization gathers for the host's diff
+#: emission (`DeviceTextDoc.touched_rows`). Static, so the touched-slot
+#: vector adds no program shape; a round that touches more slots, a
+#: rebuild and a bulk load read the whole state instead.
+TOUCH_K = 64
+
 
 def run_head_fields(plan, batch_rank, ta, tc, pa, pc) -> dict:
     """Run-head planning fields that are a pure function of the (immutable)
@@ -301,6 +307,12 @@ class DeviceTextDoc(CausalDeviceDoc):
         self._pos_cache = None
         self._text_cache = None               # host text + per-seg table
         self._touched_old = []                # assign-target slots since cache
+        self._dirty = []                      # assign-target slots since
+        # clear_dirty(), the diff emission's feed; None past TOUCH_K
+        self._touch_req = None                # slots the next position
+        # materialization gathers (touched_rows)
+        self._mat_touch = None                # slots the cached one gathered
+        self._touch_rows = None               # their fetched rows
         self.pull_stats: Optional[dict] = None  # how the LAST text() pulled
 
     # ------------------------------------------------------------------
@@ -911,7 +923,24 @@ class DeviceTextDoc(CausalDeviceDoc):
             # assign targets are pre-round slots: the text-cache spans they
             # fall in must re-pull (visibility/content may have changed)
             self._touched_old.append(plan.touched_slots)
+        if plan.touched_slots is not None and self._dirty is not None:
+            self._dirty.append(plan.touched_slots)
+            if sum(map(len, self._dirty)) > TOUCH_K:
+                self._dirty = None
         self._invalidate()
+
+    def dirty_slots(self) -> Optional[np.ndarray]:
+        """Slots a set, del or inc wrote since `clear_dirty()` (insertions
+        are not listed: they are the slots above the count at that
+        point); None when more than TOUCH_K, or after a failed round."""
+        if self._dirty is None:
+            return None
+        if not self._dirty:
+            return np.empty(0, np.int64)
+        return np.concatenate(self._dirty)
+
+    def clear_dirty(self):
+        self._dirty = []
 
     def _execute_plan(self, b: TextChangeBatch, plan: "_RoundExec"):
         """Commit a planned round: index/count bookkeeping + device
@@ -1134,6 +1163,16 @@ class DeviceTextDoc(CausalDeviceDoc):
         else:
             n = np.int32(self.n_elems)
         self._count_dispatch(label="materialize")  # one materialize program
+        touched = ()
+        if with_pos:
+            # one static int32[TOUCH_K] shape, padded with the head slot,
+            # whether or not any slot is asked for
+            req = self._touch_req
+            vec = np.zeros(TOUCH_K, np.int32)
+            if req is not None:
+                vec[: len(req)] = req
+            touched = (vec,)
+            self._mat_touch = req
         if (self.prefer_planned and self.seg_mirror is not None
                 and self.seg_mirror.n_segs + 2 <= S):
             # host-planned structure: device skips the structural S-stage
@@ -1143,26 +1182,34 @@ class DeviceTextDoc(CausalDeviceDoc):
                   else materialize_codes_planned)
             return fn(dev["parent"], dev["ctr"], dev["actor"],
                       dev["value"], dev["has_value"], dev["chain"], n,
-                      segplan, S=S, as_u8=as_u8, L=L)
+                      segplan, *touched, S=S, as_u8=as_u8, L=L)
         fn = materialize_text if with_pos else materialize_codes
         return fn(dev["parent"], dev["ctr"], dev["actor"], dev["value"],
-                  dev["has_value"], dev["chain"], n,
+                  dev["has_value"], dev["chain"], n, *touched,
                   S=S, as_u8=as_u8, L=L)
 
     def _scalars(self) -> np.ndarray:
         """Fetch [n_vis, n_segs] of the cached materialization (the one
         device->host sync of the read path); verifies the S bucket actually
-        fit and re-runs bigger if the host bound was ever stale."""
+        fit and re-runs bigger if the host bound was ever stale. A position
+        materialization's touched-slot rows ride the same transfer
+        (`touched_rows`); they are dropped when the mirror had to heal."""
         if self._scal is None:
-            from ..ops.ingest import bucket
+            from ..ops.ingest import TOUCHED_ROWS, bucket
             if self._mat is None:
                 self._materialize(with_pos=False)
             heals = 0
             while True:
-                scalars = np.asarray(self._mat[-1])
-                self._count_sync(label="scalars_fetch",  # the read path's
-                                 # one device sync
-                                 d2h_bytes=scalars.nbytes)
+                fetched = np.asarray(self._mat[-1])
+                if self._mat_touch is not None and len(self._mat) == 3:
+                    self._count_sync(label="touched_fetch",
+                                     d2h_bytes=fetched.nbytes)
+                else:
+                    self._count_sync(label="scalars_fetch",  # the read
+                                     # path's one device sync
+                                     d2h_bytes=fetched.nbytes)
+                n_rows = TOUCHED_ROWS * TOUCH_K if len(self._mat) == 3 else 0
+                scalars = fetched[: len(fetched) - n_rows]
                 n_segs = int(scalars[1])
                 if len(scalars) == 5:
                     # planned materialization: verify the host mirror against
@@ -1203,7 +1250,32 @@ class DeviceTextDoc(CausalDeviceDoc):
                 self._mat_S = S
             self._seg_bound = n_segs  # tighten for the next materialize
             self._scal = scalars
+            self._touch_rows = (fetched[len(scalars):].reshape(
+                TOUCHED_ROWS, TOUCH_K) if n_rows and not heals else None)
         return self._scal
+
+    def touched_rows(self, slots: np.ndarray) -> Optional[np.ndarray]:
+        """(TOUCHED_ROWS, k) int32 of the current state at k <= TOUCH_K
+        distinct slots in 1..n_elems: RGA position, visible elements before
+        it in list order, visibility, value, actor rank, counter — from one
+        position materialization and its one scalars transfer. None when
+        the slots do not fit the vector, the element-wise kernel is in use,
+        or the fetch healed the segment mirror: the caller then reads the
+        whole state (`_positions`, `_mirrors`)."""
+        if (len(slots) > TOUCH_K or not self.use_condensed
+                or not self.n_elems):
+            return None
+        self._touch_req = slots
+        try:
+            if (self._mat is None or len(self._mat) != 3
+                    or not np.array_equal(self._mat_touch, slots)):
+                self._mat = None   # a cached one gathered other slots
+                self._materialize(with_pos=True)
+            self._scalars()
+        finally:
+            self._touch_req = None
+        rows = self._touch_rows
+        return None if rows is None else rows[:, : len(slots)]
 
     def _rebuild_mirror(self) -> Optional[SegmentMirror]:
         """Heal path: reconstruct the segment mirror from the real device
@@ -1528,9 +1600,11 @@ class DeviceTextDoc(CausalDeviceDoc):
 
     def _plan_failed(self):
         # a raising round may have partially mutated device tables; the
-        # host text cache can no longer be trusted to diff against
+        # host text cache and the dirty-slot feed can no longer be trusted
+        # to diff against
         self._text_cache = None
         self._touched_old = []
+        self._dirty = None
 
     def values(self) -> list:
         h = self._mirrors()

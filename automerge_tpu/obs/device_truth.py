@@ -137,6 +137,7 @@ SYNC_LABELS = frozenset({
     "mirror_fetch",           # packed host-mirror fetch (pack_rows)
     "slow_info_fetch",        # the residual path's one packed round trip
     "scalars_fetch",          # read path's visible-count fetch
+    "touched_fetch",          # the same, with the touched slots' rows
     "positions_fetch",        # RGA position pull
     "codes_pull",             # O(doc) codes buffer pull
     "segment_visible_counts",  # incremental pull per-segment counts

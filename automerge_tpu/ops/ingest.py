@@ -685,7 +685,7 @@ def _linearize_segments(parent, attach_off, ctr, actor, weight, valid):
 
 
 def _materialize_core(parent, ctr, actor, value, has_value, chain, n_elems,
-                      S, with_pos, as_u8):
+                      S, with_pos, as_u8, touched=None):
     """RGA positions + visible compaction from the maintained chain bits.
 
     Segments (maximal chain runs, contiguous in slot space) compact into S
@@ -783,8 +783,27 @@ def _materialize_core(parent, ctr, actor, value, has_value, chain, n_elems,
     if with_pos:
         pos = jnp.where(is_elem, starts_exp + (idx - seg_head_exp),
                         jnp.where(idx == 0, -1, C + 1))
-        return pos, codes, scalars
+        return pos, codes, _with_touched_rows(
+            scalars, touched, pos, vis_rank, vis, value, actor, ctr)
     return codes, scalars
+
+
+TOUCHED_ROWS = 6   # pos, visible prefix, visible, value, actor, ctr
+
+
+def _with_touched_rows(scalars, touched, pos, vis_rank, vis, value, actor,
+                       ctr):
+    """The scalars, followed by TOUCHED_ROWS rows of K int32 gathered at
+    the `touched` slots (int32[K], padded with slot 0): each slot's RGA
+    position, the count of visible elements before it in list order, its
+    visibility, value, actor rank and counter. The host's diff emission
+    reads a round's changed slots from this one small transfer instead of
+    pulling the position vector and the element tables
+    (backend/device.py `_text_diffs`)."""
+    t = jnp.clip(touched, 0, pos.shape[0] - 1)
+    rows = jnp.stack([pos[t], vis_rank[t], vis[t].astype(jnp.int32),
+                      value[t], actor[t], ctr[t]])
+    return jnp.concatenate([scalars, rows.reshape(-1)])
 
 
 # Odd 32-bit mixing constants (Knuth golden-ratio / murmur3) for the
@@ -819,7 +838,8 @@ def mix32_np(x: np.ndarray) -> np.ndarray:
 
 
 def _materialize_core_planned(parent, ctr, actor, value, has_value, chain,
-                              n_elems, segplan, S, with_pos, as_u8):
+                              n_elems, segplan, S, with_pos, as_u8,
+                              touched=None):
     """Materialization with HOST-PLANNED segment structure.
 
     `segplan` is the (4, S) int32 matrix from
@@ -914,20 +934,23 @@ def _materialize_core_planned(parent, ctr, actor, value, has_value, chain,
     if with_pos:
         pos = jnp.where(is_elem, starts_exp + (idx - seg_head_exp),
                         jnp.where(idx == 0, -1, C + 1))
-        return pos, codes, scalars
+        return pos, codes, _with_touched_rows(
+            scalars, touched, pos, vis_rank, vis, value, actor, ctr)
     return codes, scalars
 
 
 @partial(jax.jit, static_argnames=("S", "as_u8", "L"))
 def materialize_text_planned(parent, ctr, actor, value, has_value, chain,
-                             n_elems, segplan,
+                             n_elems, segplan, touched,
                              *, S: int, as_u8: bool = False, L: int = None):
     """`materialize_text` with host-planned segment structure (see
-    `_materialize_core_planned`). parent/ctr/actor feed only the
-    plan-consistency hash reduces, not the linearization."""
+    `_materialize_core_planned`). parent/ctr/actor feed the
+    plan-consistency hash reduces and the touched-slot rows, not the
+    linearization."""
     cols = _slice_live((parent, ctr, actor, value, has_value, chain), L)
     return _materialize_core_planned(*cols, n_elems, segplan, S,
-                                     with_pos=True, as_u8=as_u8)
+                                     with_pos=True, as_u8=as_u8,
+                                     touched=touched)
 
 
 @partial(jax.jit, static_argnames=("S", "as_u8", "L"))
@@ -1008,13 +1031,16 @@ def segment_visible_counts(has_value, n_elems, segplan,
 
 @partial(jax.jit, static_argnames=("S", "as_u8", "L"))
 def materialize_text(parent, ctr, actor, value, has_value, chain, n_elems,
-                     *, S: int, as_u8: bool = False, L: int = None):
-    """Full materialization: (pos, codes, [n_vis, n_segs]). `pos` includes
-    tombstones (head = -1, padding > n); `codes` is visible values scattered
-    into list order (uint8 when `as_u8` — the host tracks 7-bit-ness). The
-    host retries with a bigger S when n_segs+1 > S."""
+                     touched, *, S: int, as_u8: bool = False, L: int = None):
+    """Full materialization: (pos, codes, [n_vis, n_segs] + touched-slot
+    rows). `pos` includes tombstones (head = -1, padding > n); `codes` is
+    visible values scattered into list order (uint8 when `as_u8` — the
+    host tracks 7-bit-ness); the rows are gathered at the int32[K]
+    `touched` slots (`_with_touched_rows`). The host retries with a
+    bigger S when n_segs+1 > S."""
     cols = _slice_live((parent, ctr, actor, value, has_value, chain), L)
-    return _materialize_core(*cols, n_elems, S, with_pos=True, as_u8=as_u8)
+    return _materialize_core(*cols, n_elems, S, with_pos=True, as_u8=as_u8,
+                             touched=touched)
 
 
 @partial(jax.jit, static_argnames=("S", "as_u8", "L"))

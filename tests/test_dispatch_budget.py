@@ -13,7 +13,11 @@ Pinned here:
 - the residual slow-register path costs exactly ONE blocking d2h sync
   (the packed slow_info fetch) regardless of op count, and the packed
   one-upload writeback is byte-equivalent to the legacy six-transfer
-  path.
+  path;
+- a one-character remote insert into a 6,000-character text object is
+  the merge round plus one materialization, with ONE blocking sync: the
+  scalars and the touched slots' rows in one small transfer, no position
+  vector and no element-table mirror.
 """
 
 import numpy as np
@@ -213,3 +217,30 @@ def test_map_round_accounting():
     doc.apply_batch(MapChangeBatch.from_changes(changes, "m"))
     st = doc.dispatch_stats
     assert st["dispatches"] == 1 and st["syncs"] == 1, st
+
+
+def test_remote_insert_is_two_dispatches_one_sync():
+    """The served rooms' change shape (tests/test_touched_diffs.py
+    `typed_room`): the patch comes from the touched slots' rows, which
+    ride the materialization's scalars transfer (`touched_fetch`)."""
+    from test_touched_diffs import typed_room
+
+    _ds, deliver = typed_room(6000)
+    for _ in range(4):
+        deliver()
+    for _ in range(6):
+        before = accounting.labeled_snapshot()
+        with accounting.track() as t:
+            deliver()
+        after = accounting.labeled_snapshot()
+        labels = {kind: {k: v["n"] - before[kind].get(k, {"n": 0})["n"]
+                         for k, v in after[kind].items()
+                         if v["n"] != before[kind].get(k, {"n": 0})["n"]}
+                  for kind in ("dispatch", "sync")}
+        assert labels == {"dispatch": {"fused_mixed_round": 1,
+                                       "materialize": 1},
+                          "sync": {"touched_fetch": 1}}, labels
+        assert (t.thread_stats["dispatches"], t.thread_stats["syncs"]) \
+            == (2, 1), t.thread_stats
+        # scalars + 6 rows of 64 int32, not O(doc) columns
+        assert t.thread_stats["d2h_bytes"] < 2048, t.thread_stats
