@@ -26,6 +26,9 @@ Architecture decisions (the why, not just the what):
   wire decode on the >=64-op engine path), and every room hub runs the
   tick inside ``hub.batched()`` so N deliveries + clock reveals cost one
   vectorized flush per room — the PR-5 planner amortized across tenants.
+  The flush runs as soon as the room's last group of the tick has
+  applied (on the lane-worker leg, at the barrier), so a room's peers
+  do not wait for the rest of the tick's applies.
 - **Degradation ladder** (each rung typed + counted + obs-evented, and
   strictly per-tenant): budget deferral (``svc/defer``) -> deadline shed
   of the lowest-priority tail (``svc/shed``) -> credit exhaustion
@@ -45,7 +48,7 @@ from __future__ import annotations
 
 import math
 import time
-from collections import deque
+from collections import Counter, deque
 from contextlib import ExitStack, nullcontext
 
 from .. import obs
@@ -62,12 +65,12 @@ from .budget import ServiceConfig, TenantBudget, approx_msg_bytes
 LIVE, SUSPECT, DEAD = "live", "suspect", "dead"
 
 
-def _lap(name: str, t0: int) -> int:
+def _lap(name: str, t0: int, args: dict | None = None) -> int:
     """Emit the ``svc/<name>`` child span of the tick from ``t0`` to now
     and return now, the next child's start. Call only behind
     ``obs.ENABLED``."""
     t1 = obs.now()
-    obs.span("svc", name, t0, t1_ns=t1)
+    obs.span("svc", name, t0, args=args, t1_ns=t1)
     return t1
 
 
@@ -277,7 +280,8 @@ class SyncService:
                       "protocol_errors": 0, "max_starved_streak": 0,
                       "peak_inbox": 0, "peak_parked": 0, "peak_recv_buf": 0,
                       "peak_lag_ops": 0, "peak_lag_ticks": 0,
-                      "backpressured_closed": 0, "retransmits_closed": 0}
+                      "backpressured_closed": 0, "retransmits_closed": 0,
+                      "early_flushes": 0}
 
     def _note(self, kind: str, **args):
         """Append one degradation/lifecycle event to the bounded
@@ -395,11 +399,15 @@ class SyncService:
     def tick(self):
         """One scheduler round: budgeted cross-tenant admission (grouped
         per doc), retransmission, peer-health escalation, evictions, and
-        one deferred hub flush per room.
+        one deferred hub flush per room, run right after the room's last
+        group of the tick (or at the tick's end for a room that only
+        took clock reveals or was dirtied after its groups).
 
         With tracing on, the ``svc/tick`` span is covered by its
         children, in order: ``svc/admit``, one ``svc/deliver`` per
-        (room, doc) group, ``svc/sessions``, ``svc/flush``, ``svc/mesh``
+        (room, doc) group, each room's ``svc/flush`` (``phase:
+        "deliver"``) after its last group, ``svc/sessions``, the
+        tick-end ``svc/flush`` (``phase: "tick"``), ``svc/mesh``
         (residency tier only) and ``svc/lag`` — a fixed number of
         records per tick for the passes over every session."""
         t0 = obs.now() if obs.ENABLED else 0
@@ -415,8 +423,10 @@ class SyncService:
         #                         [changes, senders, frames]
         shed = 0
         with ExitStack() as stack:
-            # every room hub defers its flushes to ONE flush per room at
-            # stack exit — the tick's cross-tenant amortization
+            # every room hub defers its flushes to ONE flush per room —
+            # the tick's cross-tenant amortization. The delivery phase
+            # runs it right after the room's last group; the stack's
+            # exit flushes whatever is still pending
             for room in list(self._rooms.values()):
                 stack.enter_context(room.hub.batched())
             for i, sess in enumerate(self._admission_order()):
@@ -467,7 +477,7 @@ class SyncService:
             # pipelining on (INTERNALS §24) the groups fan out to the
             # lane workers concurrently, still inside the deferred-
             # flush stack — the one-flush-per-room amortization is
-            # preserved at the barrier
+            # preserved at the barrier, where the caller flushes
             self._deliver_groups(groups)
             t = obs.now() if obs.ENABLED else 0
             # retransmission (may declare peers dead via on_dead)
@@ -480,9 +490,9 @@ class SyncService:
                 self.evict(sess.tenant_id, sess.pending_dead)
             if obs.ENABLED:
                 t = _lap("sessions", t)
-        # the stack's exit ran every touched room's deferred hub flush
+        # the stack's exit ran every deferred hub flush still pending
         if obs.ENABLED:
-            t = _lap("flush", t)
+            t = _lap("flush", t, {"phase": "tick"})
         if self._doc_mesh is not None:
             # the residency tier's tick-loop paging hooks: drain the
             # bulk-mesh backlog through the paging gate (deliver_round
@@ -563,37 +573,67 @@ class SyncService:
         fans each touched lane's groups to that lane's worker (a room
         belongs to exactly ONE lane, so workers never share gate/hub/
         doc state) while the caller pre-decodes the NEXT tick's queued
-        frames; service-global stats fold after the barrier. The
-        sequential loop below is the parity comparator — identical
-        gate calls in identical per-lane order."""
-        ex = self._mesh_executor() if groups else None
+        frames; service-global stats fold after the barrier, and the
+        caller then flushes the rooms the workers touched (transport
+        callbacks stay on the service thread). The sequential leg is
+        the parity comparator — identical gate calls in identical
+        per-lane order."""
+        items = []
+        for key, payload in groups.items():
+            room = self._rooms.get(key[0])
+            if room is not None:
+                items.append((key, room, payload))
+        ex = self._mesh_executor() if items else None
         if ex is not None:
             by_lane: dict = {}
             rest = []
-            for key, payload in groups.items():
-                room = self._rooms.get(key[0])
-                if room is None:
-                    continue
-                if room.lane is None:
-                    rest.append((key, room, payload))
+            for item in items:
+                lane = item[1].lane
+                if lane is None:
+                    rest.append(item)
                 else:
-                    by_lane.setdefault(room.lane.index, []).append(
-                        (key, room, payload))
+                    by_lane.setdefault(lane.index, []).append(item)
             if len(by_lane) > 1:
-                tasks = [ex.submit(idx, self._deliver_lane_groups, items)
-                         for idx, items in sorted(by_lane.items())]
+                lanes = sorted(by_lane.items())
+                tasks = [ex.submit(idx, self._deliver_lane_groups, group)
+                         for idx, group in lanes]
                 ex.barrier(tasks, while_waiting=lambda:
                            self._overlap_host_work(ex, tasks))
                 for task in tasks:
                     self._fold_deliveries(task.result)
-                for key, room, payload in rest:
-                    self._deliver_one_group(key, room, payload)
+                touched = {room.room_id: room for _idx, group in lanes
+                           for _key, room, _payload in group}
+                for room in touched.values():
+                    self._flush_early(room)
+                self._deliver_in_turn(rest)
                 return
-        for key, payload in groups.items():
-            room = self._rooms.get(key[0])
-            if room is None:
-                continue
+        self._deliver_in_turn(items)
+
+    def _deliver_in_turn(self, items):
+        """The sequential leg: each (key, room, payload) group in order,
+        and each room's deferred hub flush right after its last group,
+        so its peers get the room's changes without waiting for the
+        rest of the tick's applies."""
+        left = Counter(key[0] for key, _room, _payload in items)
+        for key, room, payload in items:
             self._deliver_one_group(key, room, payload)
+            left[key[0]] -= 1
+            if not left[key[0]]:
+                self._flush_early(room)
+
+    def _flush_early(self, room: Room):
+        """Run the room's deferred hub flush inside the tick, once its
+        groups have applied: a ``svc/flush`` span with ``phase:
+        "deliver"``, outside every ``svc/deliver``. Nothing runs when
+        the deliveries left the hub clean (every change parked or was a
+        duplicate)."""
+        t0 = obs.now() if obs.ENABLED else 0
+        if not room.hub.flush_deferred():
+            return
+        self.stats["early_flushes"] += 1
+        if obs.ENABLED:
+            obs.span("svc", "flush", t0,
+                     args={"room": room.room_id, "phase": "deliver"})
 
     def _deliver_one_group(self, key, room, payload):
         """One (room, doc) group through the gate — the sequential leg,

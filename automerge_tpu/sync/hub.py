@@ -221,9 +221,23 @@ class SyncHub:
             yield self
         finally:
             self._defer_depth -= 1
-            if not self._defer_depth and self._flush_wanted:
-                self._flush_wanted = False
-                self.flush()
+            if not self._defer_depth:
+                self.flush_deferred()
+
+    def flush_deferred(self) -> bool:
+        """Run the flush a ``batched()`` block deferred so far, now,
+        staying inside the block: a later change in it defers again and
+        flushes at the outermost exit. Returns whether a flush ran (none
+        does when nothing is pending)."""
+        if not self._flush_wanted:
+            return False
+        self._flush_wanted = False
+        depth, self._defer_depth = self._defer_depth, 0
+        try:
+            self.flush()
+        finally:
+            self._defer_depth = depth
+        return True
 
     def flush(self):
         """One batched comparison; send changes for every flagged pair.

@@ -172,7 +172,8 @@ def test_tick_children_nest_in_their_parent(typed_tick, cat, name, parent):
 
 
 def test_tick_children_cover_the_tick(typed_tick):
-    """Each tick has one admit, sessions, flush and lag span, its direct
+    """Each tick has one admit, sessions and lag span, one tick-end
+    flush and one in-delivery flush per touched room, its direct
     children never overlap, and together they cover at least 90% of
     the ticks' time."""
     ticks = _named(typed_tick, "svc", "tick")
@@ -181,8 +182,13 @@ def test_tick_children_cover_the_tick(typed_tick):
         kids = sorted((r for n in CHILDREN
                        for r in _named(typed_tick, "svc", n)
                        if _inside(r, tick)), key=lambda r: r[0])
-        for n in ("admit", "sessions", "flush", "lag"):
+        for n in ("admit", "sessions", "lag"):
             assert sum(1 for r in kids if r[3] == n) == 1
+        phases = [r[5]["phase"] for r in kids if r[3] == "flush"]
+        assert phases.count("tick") == 1
+        early = [r[5]["room"] for r in kids if r[3] == "flush"
+                 and r[5]["phase"] == "deliver"]
+        assert sorted(early) == ["r0", "r1"]
         for a, b in zip(kids, kids[1:]):
             assert a[0] + a[1] <= b[0]
         covered += sum(r[1] for r in kids)

@@ -451,3 +451,61 @@ def test_lossy_network_recovers_on_reconnect():
         assert len(states) >= 2, f"seed {seed}: doc never spread"
         assert all(s == states[0] for s in states), \
             f"seed {seed}: diverged after reconnect: {states}"
+
+
+def _revealed_hub(n_peers=2):
+    """A hub over one doc whose peers have all revealed its clock;
+    returns (doc set, hub, {peer: outbox})."""
+    ds = DocSet()
+    hub = SyncHub(ds)
+    boxes = {f"p{i}": [] for i in range(n_peers)}
+    handles = {p: hub.add_peer(p, boxes[p].append) for p in boxes}
+    hub.open()
+    ds.set_doc("doc1", am.change(am.init("alice"),
+                                 lambda d: d.__setitem__("x", 0)))
+    for h in handles.values():
+        h.receive_msg({"docId": "doc1", "clock": {}})
+    for box in boxes.values():
+        box.clear()
+    return ds, hub, boxes
+
+
+def test_flush_deferred_is_a_noop_when_nothing_is_pending():
+    _ds, hub, boxes = _revealed_hub()
+    with mock.patch.object(SyncHub, "flush", wraps=hub.flush) as spy:
+        assert hub.flush_deferred() is False
+        with hub.batched():
+            assert hub.flush_deferred() is False
+        assert spy.call_count == 0
+    assert all(box == [] for box in boxes.values())
+
+
+def test_flush_deferred_sends_now_and_a_later_change_flushes_at_exit():
+    """Inside one ``batched()`` block: the pending flush runs at once,
+    the block keeps deferring, and a change made after it flushes once
+    at the block's exit."""
+    ds, hub, boxes = _revealed_hub()
+
+    def edit(key):
+        ds.set_doc("doc1", am.change(ds.get_doc("doc1"),
+                                     lambda d: d.__setitem__(key, 1)))
+
+    def carries(m):
+        return bool(m.get("changes")) or m.get("wire") is not None
+
+    def sent():
+        return [sum(map(carries, box)) for box in boxes.values()]
+
+    with hub.batched():
+        edit("y")
+        assert sent() == [0, 0]
+        assert hub.flush_deferred() is True
+        assert sent() == [1, 1]
+        assert hub.flush_deferred() is False
+        edit("z")
+        assert sent() == [1, 1]         # still deferred
+    assert sent() == [2, 2]
+    for box in boxes.values():
+        last = [m for m in box if carries(m)][-1]
+        assert last["clock"] == dict(
+            Frontend.get_backend_state(ds.get_doc("doc1")).clock)
